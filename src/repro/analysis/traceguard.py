@@ -4,7 +4,7 @@ The engine's trace-count stats (``stats["decode_traces"]``) are
 hand-incremented inside the traced bodies — informative, but nothing fails
 when a new protocol combination sneaks in a retrace. :class:`TraceGuard`
 hooks the one chokepoint every jit trace passes through
-(``jax._src.interpreters.partial_eval.trace_to_jaxpr_dynamic``) and raises
+(``jax._src.interpreters.partial_eval.trace_to_jaxpr``) and raises
 :class:`TraceGuardError` — with the offending avals and every aval set seen
 before — the moment a watched function traces more often than its budget.
 
@@ -20,13 +20,21 @@ functions) is recorded in :attr:`counts` but never raises. The XLA C++
 fastpath serves cache hits without re-entering Python, so a count of 1 means
 "traced exactly once" — there is no double-counting on steady-state steps.
 
+The hooked entry point is private to JAX and has moved between releases. So
+``__enter__`` traces a fresh canary function and raises if the hook did not
+see it: a guard that can no longer count traces fails loudly instead of
+passing every budget with counts of zero.
+
 ``conftest.py`` exposes this as the ``trace_guard`` pytest fixture.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 from jax._src.interpreters import partial_eval as _pe
+
+_CANARY = "_traceguard_canary"
 
 
 class TraceGuardError(AssertionError):
@@ -62,18 +70,40 @@ class TraceGuard:
         if self._active:
             raise RuntimeError("TraceGuard is not re-entrant")
         self._active = True
-        self._orig = _pe.trace_to_jaxpr_dynamic
+        self._orig = _pe.trace_to_jaxpr
         guard = self
 
         def traced(fun: Any, in_avals: Any, *args: Any, **kwargs: Any) -> Any:
             guard._record(fun, in_avals)
             return guard._orig(fun, in_avals, *args, **kwargs)
 
-        _pe.trace_to_jaxpr_dynamic = traced
+        _pe.trace_to_jaxpr = traced
+        try:
+            self._check_canary()
+        except BaseException:
+            _pe.trace_to_jaxpr = self._orig
+            self._active = False
+            raise
         return self
 
+    def _check_canary(self) -> None:
+        """Trace a function no cache has seen; the hook must count it."""
+        def canary(x: Any) -> Any:
+            return x + 1
+
+        canary.__name__ = _CANARY
+        jax.jit(canary).trace(0)
+        seen = self.counts.pop(_CANARY, 0)
+        self.avals.pop(_CANARY, None)
+        if seen != 1:
+            raise RuntimeError(
+                "TraceGuard cannot see jit traces: its hook on "
+                "jax._src.interpreters.partial_eval.trace_to_jaxpr counted "
+                f"{seen} trace(s) of a fresh function (jax {jax.__version__}). "
+                "The tracing entry point moved; re-point the hook.")
+
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
-        _pe.trace_to_jaxpr_dynamic = self._orig
+        _pe.trace_to_jaxpr = self._orig
         self._active = False
         if exc_type is None:
             for name, want in self.exact.items():

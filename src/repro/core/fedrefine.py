@@ -62,15 +62,17 @@ class FedRefineSystem:
               channel: Optional[ParaphraseChannel] = None,
               wire: Optional[TR.Channel] = None, *,
               audit_wire: bool = False,
-              wire_schemas: Optional[dict] = None) -> "FedRefineSystem":
-        """``audit_wire=True`` wraps the wire in a
+              wire_schemas: Optional[dict] = None,
+              seed: int = 0) -> "FedRefineSystem":
+        """``seed`` seeds the registry's fusers and gates (the same in every
+        process). ``audit_wire=True`` wraps the wire in a
         :class:`~repro.analysis.wire_audit.WireAuditor`: every transmitted
         message is verified against the protocol's declared WireSchema
         (media, dtypes, codec stages, commload byte accounting) and
         violations raise naming the producing call site. ``wire_schemas``
         overrides the registry defaults (else they are derived from the
         wire's codec composition)."""
-        reg = FuserRegistry({m.name: m.cfg for m in members})
+        reg = FuserRegistry({m.name: m.cfg for m in members}, seed=seed)
         reg.ensure_all_pairs()
         wire = wire or TR.IdentityChannel()
         if audit_wire:

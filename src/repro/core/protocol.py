@@ -24,9 +24,9 @@ Latency model per link:
   latency_c2c = kv_bytes(seq)/bw + rtt + fuser_time + decode_time
   latency_t2t = tx_gen_time + text_bytes/bw + rtt + rx_prefill_time + decode_time
 
-Compute-time terms come from the TPU-v5e roofline constants (repro/hw.py — one
-shared source with roofline.py), so the protocol's decisions stay consistent
-with the §Roofline tables. Properties pinned by tests: decisions are monotone
+Compute-time terms come from the TPU v5e row of the per-device peak table
+(repro/hw.py — one shared source with roofline.py), so the protocol's
+decisions stay consistent with the §Roofline tables. Properties pinned by tests: decisions are monotone
 in bandwidth (more bandwidth never flips C2C→T2T) and respect QoS feasibility.
 """
 from __future__ import annotations
@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import commload
-from repro.hw import HBM_BW, PEAK_FLOPS  # shared with roofline.py  # noqa: F401
+from repro.hw import V5E  # the chip the latency model assumes
 from repro.models.cache import FusedPrefix
 
 
@@ -60,12 +60,12 @@ class QoS:
 
 
 def _prefill_time(cfg: ModelConfig, seq: int, mfu: float = 0.4) -> float:
-    return 2.0 * cfg.active_param_count() * seq / (PEAK_FLOPS * mfu)
+    return 2.0 * cfg.active_param_count() * seq / (V5E.bf16_flops * mfu)
 
 
 def _decode_time(cfg: ModelConfig, steps: int, hbm_frac: float = 0.6) -> float:
     # decode is memory-bound: one full weight read per token
-    return steps * 2.0 * cfg.active_param_count() / (HBM_BW * hbm_frac)
+    return steps * 2.0 * cfg.active_param_count() / (V5E.hbm_bw * hbm_frac)
 
 
 def _fuser_time(cfg_tx: ModelConfig, cfg_rx: ModelConfig, seq: int,
@@ -75,7 +75,7 @@ def _fuser_time(cfg_tx: ModelConfig, cfg_rx: ModelConfig, seq: int,
     d_h = max(d_in, d_out)
     n = len(cfg_rx.attention_layers)
     flops = 2.0 * seq * n * (d_in * d_h + d_h * d_h + d_h * d_out)
-    return flops / (PEAK_FLOPS * mfu)
+    return flops / (V5E.bf16_flops * mfu)
 
 
 # ------------------------------------------------------------ prepared form

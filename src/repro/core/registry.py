@@ -7,6 +7,7 @@ checkpoint/checkpoint.py so a deployment can restart with its trained fusers.
 """
 from __future__ import annotations
 
+import zlib
 from typing import Dict, Iterable, Optional, Tuple
 
 import jax
@@ -16,15 +17,25 @@ from repro.core import fuser as F
 
 
 class FuserRegistry:
-    def __init__(self, models: Dict[str, ModelConfig]):
+    """``seed`` seeds every fuser and gate created without an explicit key:
+    each draws from a key folded from the seed and its member names, so a
+    registry is the same in every process."""
+
+    def __init__(self, models: Dict[str, ModelConfig], seed: int = 0):
         self.models = dict(models)
+        self.seed = seed
         self.fusers: Dict[Tuple[str, str], dict] = {}
         self.gating: Dict[str, dict] = {}  # per receiver
+
+    def _key(self, *names: str) -> jax.Array:
+        # a stable digest: Python's str hash is salted per process
+        digest = zlib.crc32("\x1f".join(names).encode()) % (2**31)
+        return jax.random.fold_in(jax.random.PRNGKey(self.seed), digest)
 
     # ------------------------------------------------------------- creation
     def ensure_fuser(self, tx: str, rx: str, key=None, **kw) -> dict:
         if (tx, rx) not in self.fusers:
-            key = key if key is not None else jax.random.PRNGKey(hash((tx, rx)) % (2**31))
+            key = key if key is not None else self._key("fuser", tx, rx)
             self.fusers[(tx, rx)] = F.init_fuser(self.models[tx], self.models[rx],
                                                  key, **kw)
         return self.fusers[(tx, rx)]
@@ -47,7 +58,7 @@ class FuserRegistry:
     def ensure_gating(self, rx: str, key=None) -> dict:
         from repro.core.gating import init_gating
         if rx not in self.gating:
-            key = key if key is not None else jax.random.PRNGKey(hash(rx) % (2**31))
+            key = key if key is not None else self._key("gating", rx)
             self.gating[rx] = init_gating(self.models[rx], key)
         return self.gating[rx]
 

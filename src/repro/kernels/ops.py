@@ -1,14 +1,13 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode — the
-kernel body runs in Python with real BlockSpec tiling semantics — so the same
-call sites work on TPU unchanged. ``interpret`` auto-detects the backend unless
-forced via REPRO_PALLAS_INTERPRET=0/1.
+Off the TPU (the CPU test backend) the kernels execute in ``interpret=True``
+mode — the kernel body runs in Python with real BlockSpec tiling semantics — so
+the same call sites work on TPU unchanged. The backend alone decides: on a TPU
+the kernels always compile through Mosaic, never the interpreter.
 """
 from __future__ import annotations
 
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +19,6 @@ from repro.kernels.gated_fusion import gated_fusion_pallas
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
     return jax.default_backend() != "tpu"
 
 

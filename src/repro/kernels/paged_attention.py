@@ -82,8 +82,8 @@ def _kernel(pm_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_out, l_out,
         seen = m_ref[...] > _NEG / 2  # (G, 1)
         o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = jnp.where(seen, o, 0.0).astype(o_ref.dtype)
-        m_out[0, 0] = m_ref[..., 0]
-        l_out[0, 0] = jnp.where(seen[:, 0], l_ref[..., 0], 0.0)
+        m_out[0, 0] = m_ref[...]
+        l_out[0, 0] = jnp.where(seen, l_ref[...], 0.0)
 
 
 def _kernel_q8(pm_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
@@ -127,8 +127,8 @@ def _kernel_q8(pm_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         seen = m_ref[...] > _NEG / 2
         o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = jnp.where(seen, o, 0.0).astype(o_ref.dtype)
-        m_out[0, 0] = m_ref[..., 0]
-        l_out[0, 0] = jnp.where(seen[:, 0], l_ref[..., 0], 0.0)
+        m_out[0, 0] = m_ref[...]
+        l_out[0, 0] = jnp.where(seen, l_ref[...], 0.0)
 
 
 def _validate(q, pool_shape, page_map, lengths):
@@ -149,7 +149,11 @@ def _paged_call(kernel_fn, q, pool_shape, pps, *, n_scales: int,
                 interpret: bool):
     """Shared pallas_call plumbing for the fp32/bf16 and int8 variants: the
     scalar-prefetch grid spec (page-map-dereferencing kv index maps), the
-    (o, m, l) out specs/shapes and the online-softmax scratch."""
+    (o, m, l) out specs/shapes and the online-softmax scratch.
+
+    m and l leave the kernel as (slots, Hkv, G, 1): Mosaic needs a block's
+    last two dims to be (8, 128)-aligned or whole, and a (G,) block of a
+    (..., G) array is neither for G < 128. The callers drop the unit axis."""
     slots, Hkv, G, hd = q.shape
     num_pages, _, page_size, _ = pool_shape
 
@@ -168,8 +172,8 @@ def _paged_call(kernel_fn, q, pool_shape, pps, *, n_scales: int,
         ] + [pl.BlockSpec((1, 1, 1, hd), kv_index)] * n_scales,
         out_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda s, h, p, pm, ln: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda s, h, p, pm, ln: (s, h, 0)),
-            pl.BlockSpec((1, 1, G), lambda s, h, p, pm, ln: (s, h, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda s, h, p, pm, ln: (s, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda s, h, p, pm, ln: (s, h, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),   # running max m
@@ -183,8 +187,8 @@ def _paged_call(kernel_fn, q, pool_shape, pps, *, n_scales: int,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((slots, Hkv, G, hd), q.dtype),
-            jax.ShapeDtypeStruct((slots, Hkv, G), jnp.float32),
-            jax.ShapeDtypeStruct((slots, Hkv, G), jnp.float32),
+            jax.ShapeDtypeStruct((slots, Hkv, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((slots, Hkv, G, 1), jnp.float32),
         ],
         interpret=interpret,
     )
@@ -204,8 +208,9 @@ def paged_decode_attention_pallas(
     _validate(q, k_pool.shape, page_map, lengths)
     call = _paged_call(_kernel, q, k_pool.shape, page_map.shape[1],
                        n_scales=0, interpret=interpret)
-    return call(page_map.astype(jnp.int32), lengths.astype(jnp.int32),
-                q, k_pool, v_pool)
+    o, m, l = call(page_map.astype(jnp.int32), lengths.astype(jnp.int32),
+                   q, k_pool, v_pool)
+    return o, m[..., 0], l[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -224,5 +229,6 @@ def paged_decode_attention_q8_pallas(
     _validate(q, k_q.shape, page_map, lengths)
     call = _paged_call(_kernel_q8, q, k_q.shape, page_map.shape[1],
                        n_scales=2, interpret=interpret)
-    return call(page_map.astype(jnp.int32), lengths.astype(jnp.int32),
-                q, k_q, v_q, k_scale, v_scale)
+    o, m, l = call(page_map.astype(jnp.int32), lengths.astype(jnp.int32),
+                   q, k_q, v_q, k_scale, v_scale)
+    return o, m[..., 0], l[..., 0]

@@ -104,8 +104,8 @@ def _kernel(seq_ref, pos_ref, len_ref, pm_ref, q_ref, k_ref, v_ref,
         seen = m_ref[...] > _NEG / 2  # (G*block_q, 1)
         o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = jnp.where(seen, o, 0.0).astype(o_ref.dtype)
-        m_out[0, 0] = m_ref[..., 0]
-        l_out[0, 0] = jnp.where(seen[:, 0], l_ref[..., 0], 0.0)
+        m_out[0, 0] = m_ref[...]
+        l_out[0, 0] = jnp.where(seen, l_ref[...], 0.0)
 
 
 def _validate(q, pool_shape, block_seq, block_pos, block_len, page_map):
@@ -128,7 +128,9 @@ def _validate(q, pool_shape, block_seq, block_pos, block_len, page_map):
 def _ragged_call(q, pool_shape, pps, *, block_q: int, interpret: bool):
     """The pallas_call plumbing: scalar-prefetch grid spec whose kv index
     maps dereference ``page_map[block_seq[b], p]`` at DMA-issue time, the
-    (o, m, l) out specs/shapes and the online-softmax scratch."""
+    (o, m, l) out specs/shapes and the online-softmax scratch. m and l
+    carry a trailing unit axis for Mosaic's block-shape rule (see
+    kernels/paged_attention._paged_call); the caller drops it."""
     n_blocks, Hkv, gbq, hd = q.shape
     num_pages, _, page_size, _ = pool_shape
 
@@ -151,8 +153,10 @@ def _ragged_call(q, pool_shape, pps, *, block_q: int, interpret: bool):
         out_specs=[
             pl.BlockSpec((1, 1, gbq, hd),
                          lambda b, h, p, bs, bp, bl, pm: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, gbq), lambda b, h, p, bs, bp, bl, pm: (b, h, 0)),
-            pl.BlockSpec((1, 1, gbq), lambda b, h, p, bs, bp, bl, pm: (b, h, 0)),
+            pl.BlockSpec((1, 1, gbq, 1),
+                         lambda b, h, p, bs, bp, bl, pm: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, gbq, 1),
+                         lambda b, h, p, bs, bp, bl, pm: (b, h, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((gbq, 1), jnp.float32),   # running max m
@@ -166,8 +170,8 @@ def _ragged_call(q, pool_shape, pps, *, block_q: int, interpret: bool):
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((n_blocks, Hkv, gbq, hd), q.dtype),
-            jax.ShapeDtypeStruct((n_blocks, Hkv, gbq), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, Hkv, gbq), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, Hkv, gbq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, Hkv, gbq, 1), jnp.float32),
         ],
         interpret=interpret,
     )
@@ -190,6 +194,7 @@ def ragged_prefill_attention_pallas(
     _validate(q, k_pool.shape, block_seq, block_pos, block_len, page_map)
     call = _ragged_call(q, k_pool.shape, page_map.shape[1],
                         block_q=block_q, interpret=interpret)
-    return call(block_seq.astype(jnp.int32), block_pos.astype(jnp.int32),
-                block_len.astype(jnp.int32), page_map.astype(jnp.int32),
-                q, k_pool, v_pool)
+    o, m, l = call(block_seq.astype(jnp.int32), block_pos.astype(jnp.int32),
+                   block_len.astype(jnp.int32), page_map.astype(jnp.int32),
+                   q, k_pool, v_pool)
+    return o, m[..., 0], l[..., 0]

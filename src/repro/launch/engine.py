@@ -503,7 +503,7 @@ class ContinuousBatchingEngine:
             if not self.max_prefix:
                 raise ValueError("engine built with max_prefix=0 cannot take "
                                  "fused prefixes")
-            fused = FusedPrefix.ensure(fused).pad(self.max_prefix)
+            fused = self.held_prefix(fused)
             if digest is None:
                 digest = fused_digest(fused)
         else:
@@ -516,6 +516,18 @@ class ContinuousBatchingEngine:
         self._queue.append(req)
         self._req_info[rid] = req
         return rid
+
+    def held_prefix(self, fused=None) -> FusedPrefix:
+        """A request's fused prefix as the engine holds it: padded to
+        ``max_prefix`` at the cache dtype, or the all-masked prefix of a
+        standalone request (``fused=None``). One dtype for both, so the
+        prefill steps trace once, and decode reads it from the cache-dtype
+        row table anyway."""
+        if fused is None:
+            return self._empty_req_fused
+        fused = FusedPrefix.ensure(fused).pad(self.max_prefix)
+        return FusedPrefix(k=fused.k.astype(self.cache_dtype),
+                           v=fused.v.astype(self.cache_dtype), bias=fused.bias)
 
     # -------------------------------------------------------------- admission
     def _bucket_len(self, S: int) -> int:
@@ -1062,11 +1074,7 @@ class ContinuousBatchingEngine:
         done, self._ready = self._ready, []
         if not self._active.any():
             return done
-        fused_rows = (jnp.asarray(self._fused_rows, jnp.int32)
-                      if self._fused is not None else None)
-        self._tok, self._table = self._decode(
-            self.params, self._table, self._tok, self._fused, fused_rows,
-            jnp.asarray(self._active))
+        self._tok, self._table = self._decode(*self._decode_args())
         self.stats["decode_steps"] += 1
         tok_host = np.asarray(self._tok)
         if self._san is not None:
@@ -1137,6 +1145,19 @@ class ContinuousBatchingEngine:
     def num_partial(self) -> int:
         """Prompts reserved for chunked prefill but not yet fully resident."""
         return len(self._partials)
+
+    def lower_decode(self):
+        """The jitted decode step lowered for the engine's current state —
+        the program every ``step()`` dispatches (``.as_text()`` shows whether
+        the Pallas kernels compiled or were interpreted). Lowering after the
+        first step reuses its trace: ``stats["decode_traces"]`` stays put."""
+        return self._decode.lower(*self._decode_args())
+
+    def _decode_args(self) -> tuple:
+        fused_rows = (jnp.asarray(self._fused_rows, jnp.int32)
+                      if self._fused is not None else None)
+        return (self.params, self._table, self._tok, self._fused, fused_rows,
+                jnp.asarray(self._active))
 
     def first_token_ready(self, rid: int) -> bool:
         """True once ``rid``'s first token exists (the TTFT marker: set at

@@ -17,9 +17,9 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Optional
 
-# TPU v5e-class constants (per chip) — single source shared with
-# core/protocol.py so latency estimates can't diverge from these tables.
-from repro.hw import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: F401
+# the dry-run targets a v5e pod: the v5e row of the per-device peak table,
+# shared with core/protocol.py so latency estimates can't diverge from it
+from repro.hw import V5E
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -141,9 +141,9 @@ def analyze(
     bytes_ = bytes_corrected if bytes_corrected is not None else raw["bytes"]
     coll_b = coll_corrected if coll_corrected is not None else raw["coll_bytes"]
 
-    compute_s = analytic_flops / (chips * PEAK_FLOPS)
-    memory_s = bytes_ / HBM_BW
-    collective_s = coll_b / (ICI_BW * ici_links)
+    compute_s = analytic_flops / (chips * V5E.bf16_flops)
+    memory_s = bytes_ / V5E.hbm_bw
+    collective_s = coll_b / (V5E.ici_bw * ici_links)
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
 

@@ -711,11 +711,28 @@ def test_traceguard_exact_counts():
 def test_traceguard_restores_hook_after_exception():
     from jax._src.interpreters import partial_eval as pe
 
-    before = pe.trace_to_jaxpr_dynamic
+    before = pe.trace_to_jaxpr
     with pytest.raises(TraceGuardError):
         with TraceGuard(exact={"missing": 1}):
             pass
-    assert pe.trace_to_jaxpr_dynamic is before
+    assert pe.trace_to_jaxpr is before
+
+
+def test_traceguard_fails_loudly_when_hook_is_blind(monkeypatch):
+    """A hook on an entry point jit no longer calls must refuse to start,
+    not report zero traces against every budget."""
+    import types
+
+    from repro.analysis import traceguard
+
+    from jax._src.interpreters import partial_eval as pe
+
+    blind = types.SimpleNamespace(trace_to_jaxpr=pe.trace_to_jaxpr)
+    monkeypatch.setattr(traceguard, "_pe", blind)
+    with pytest.raises(RuntimeError, match="cannot see jit traces"):
+        with TraceGuard(max_traces={"decode": 1}):
+            pass
+    assert blind.trace_to_jaxpr is pe.trace_to_jaxpr  # hook removed again
 
 
 # ------------------------------------------------- TraceGuard x engine

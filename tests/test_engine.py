@@ -169,10 +169,22 @@ def test_padded_prefix_mask_is_exact():
 def test_decode_jits_exactly_once_across_mixes():
     """The decode step traces once, no matter how the request mix changes
     (standalone-only -> fused-only -> mixed, different prefix lengths)."""
+    _mixes_trace_once(jnp.float32)
+
+
+def test_bf16_cache_traces_once_across_mixes():
+    """With a bf16 cache a float32 fused prefix meets the cache-dtype
+    all-masked prefix of standalone requests: the engine holds both at the
+    cache dtype, so neither prefill nor decode retraces."""
+    _mixes_trace_once(jnp.bfloat16)
+
+
+def _mixes_trace_once(cache_dtype):
     rx, p_rx, tx, p_tx, fz = _tiny_c2c()
     key = jax.random.PRNGKey(8)
     eng = ContinuousBatchingEngine(rx, p_rx, max_slots=2, max_seq=40,
-                                   max_prefix=8, prompt_bucket=8)
+                                   max_prefix=8, prompt_bucket=8,
+                                   cache_dtype=cache_dtype)
 
     def fused_for(p):
         S = p.shape[1]

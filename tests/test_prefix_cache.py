@@ -257,13 +257,17 @@ def test_radix_clear_releases_all_pins():
 
 
 def _shared_prompts(key, n, shared_len, tail_len):
+    """n prompts of one shared prefix plus a random tail each. Tail i starts
+    with token i, so no two tails share a first token and every radix match
+    is exactly the ``shared_len`` shared tokens, whatever the random draw."""
+    assert n <= VOCAB
     shared = jax.random.randint(jax.random.fold_in(key, 99),
                                 (1, shared_len), 0, VOCAB, jnp.int32)
     out = []
     for i in range(n):
         tail = jax.random.randint(jax.random.fold_in(key, i),
                                   (1, tail_len), 0, VOCAB, jnp.int32)
-        out.append(jnp.concatenate([shared, tail], axis=1))
+        out.append(jnp.concatenate([shared, tail.at[0, 0].set(i)], axis=1))
     return out
 
 
@@ -301,9 +305,6 @@ def test_engine_shared_prefix_byte_identical(cfg, params):
 def test_engine_shared_prefix_fewer_prefill_tokens(cfg, params):
     """The capacity win: shared admissions prefill only suffixes."""
     prompts = _shared_prompts(jax.random.PRNGKey(41), 5, 24, 8)  # S=32
-    # force tails to diverge at their first token so every match is exactly
-    # the 24 shared tokens (random tails can chance-share a first token)
-    prompts = [p.at[0, 24].set(i) for i, p in enumerate(prompts)]
 
     def tokens_prefilled(pc):
         eng = ContinuousBatchingEngine(cfg, params, max_slots=8, max_seq=64,

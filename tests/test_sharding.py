@@ -124,8 +124,8 @@ def test_train_step_runs_under_host_mesh(key):
 def test_dryrun_cli_one_pair(tmp_path):
     """The dry-run CLI end-to-end on the cheapest pair (subprocess because it
     forces 512 host devices)."""
-    env = dict(os.environ, PYTHONPATH="src")
-    env.pop("JAX_PLATFORMS", None)
+    # a virtual-CPU-mesh compile: the child must never reach for libtpu
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "mamba2-130m", "--shape", "long_500k"],

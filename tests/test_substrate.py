@@ -88,6 +88,42 @@ def test_registry_checkpoint_roundtrip(tmp_path):
     assert (np.asarray(a) == np.asarray(b)).all()
 
 
+_REGISTRY_DIGEST = """
+import jax, numpy as np
+from repro.configs.case_study import tiny_zoo
+from repro.core.registry import FuserRegistry
+z = tiny_zoo()
+rx, tx = z["receiver"], z["transmitters"][0]
+reg = FuserRegistry({c.name: c for c in (rx, tx)}, seed=SEED)
+leaves = jax.tree.leaves((reg.ensure_fuser(tx.name, rx.name),
+                          reg.ensure_gating(rx.name)))
+print(repr([float(np.asarray(a, np.float64).sum()) for a in leaves]))
+"""
+
+
+def test_registry_defaults_same_in_every_process():
+    """Default fusers and gates follow the registry's seed alone, not
+    Python's per-process string hash: two processes with different hash
+    salts build the same registry, and another seed builds another."""
+    import subprocess
+    import sys
+
+    def digest(hash_seed, seed):
+        env = dict(os.environ, PYTHONHASHSEED=str(hash_seed),
+                   JAX_PLATFORMS="cpu")
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(os.path.dirname(__file__), "..", "src"),
+             env.get("PYTHONPATH", "")])
+        out = subprocess.run(
+            [sys.executable, "-c", _REGISTRY_DIGEST.replace("SEED", str(seed))],
+            env=env, capture_output=True, text=True, timeout=240)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.strip().splitlines()[-1]
+
+    assert digest(1, 0) == digest(2, 0)
+    assert digest(1, 0) != digest(1, 1)
+
+
 def test_tokenizer_roundtrip():
     from repro.data.tokenizer import ByteTokenizer
     tok = ByteTokenizer()
