@@ -109,9 +109,12 @@ class FedRefineSystem:
         for n in tx_names:
             p = self.participants[n]
             S = prompts[n].shape[1]
-            _, cache = T.prefill(p.cfg, p.params, prompts[n], max_seq=S)
-            msg = TR.stack_message(cache.export_stack(p.cfg, length=S))
-            received, nbytes = self.wire.transmit(msg)
+            with jax.profiler.TraceAnnotation("fr.tx_prefill", tx=n):
+                _, cache = T.prefill(p.cfg, p.params, prompts[n], max_seq=S)
+            with jax.profiler.TraceAnnotation("fr.tx_export"):
+                msg = TR.stack_message(cache.export_stack(p.cfg, length=S))
+            with jax.profiler.TraceAnnotation("fr.wire"):
+                received, nbytes = self.wire.transmit(msg)
             stacks.append(received.stack)
             wire_bytes += nbytes
         return stacks, wire_bytes
@@ -119,12 +122,13 @@ class FedRefineSystem:
     def fused_prefix(self, receiver: str, tx_names: List[str],
                      stacks: List[KVStack], *, gated: bool = True,
                      use_kernel: bool = False) -> FusedPrefix:
-        rxp = self.participants[receiver]
-        fusers = [self.registry.get(n, receiver) for n in tx_names]
-        cfg_txs = [self.participants[n].cfg for n in tx_names]
-        gating = self.registry.ensure_gating(receiver) if gated else None
-        return c2c.fused_prefix(fusers, cfg_txs, rxp.cfg, stacks,
-                                gating=gating, use_kernel=use_kernel)
+        with jax.profiler.TraceAnnotation("fr.fuse"):
+            rxp = self.participants[receiver]
+            fusers = [self.registry.get(n, receiver) for n in tx_names]
+            cfg_txs = [self.participants[n].cfg for n in tx_names]
+            gating = self.registry.ensure_gating(receiver) if gated else None
+            return c2c.fused_prefix(fusers, cfg_txs, rxp.cfg, stacks,
+                                    gating=gating, use_kernel=use_kernel)
 
     def refine_generate(
         self,
@@ -192,29 +196,32 @@ class FedRefineSystem:
         schedulable one raises rather than silently degrading to standalone.
         Requests of all kinds coexist in one decode batch; drain() (or
         engine.step()) runs them to completion."""
-        if protocol not in PROTOCOLS:
-            raise ValueError(f"unknown protocol {protocol!r}; "
-                             f"have {sorted(PROTOCOLS)}")
-        proto = PROTOCOLS[protocol]
-        eng = self.engines.get(receiver) or self.make_engine(receiver)
-        key = key if key is not None else jax.random.PRNGKey(0)
-        prompt = jnp.asarray(prompt, jnp.int32)
-        if prompt.ndim == 1:
-            prompt = prompt[None]
-        tx_names = (self.schedule(task, receiver, n_tx)
-                    if proto.needs_transmitters() else [])
-        if proto.needs_transmitters() and not tx_names:
-            raise ValueError(
-                f"protocol {protocol!r} requested but no transmitter with a "
-                f"fuser for receiver {receiver!r} is schedulable; submit with "
-                f"protocol='standalone' to run unrefined")
-        prep = proto.prepare(self, receiver, prompt, tx_names, steps=steps,
-                             key=key, gated=gated, tx_prompts=tx_prompts)
-        return eng.submit(prep.prompt, steps, fused=prep.fused,
-                          protocol=proto.name,
-                          meta={"transmitters": tx_names,
-                                "wire_bytes": prep.wire_bytes}
-                          if tx_names else {})
+        with jax.profiler.TraceAnnotation("fr.submit", protocol=protocol):
+            if protocol not in PROTOCOLS:
+                raise ValueError(f"unknown protocol {protocol!r}; "
+                                 f"have {sorted(PROTOCOLS)}")
+            proto = PROTOCOLS[protocol]
+            eng = self.engines.get(receiver) or self.make_engine(receiver)
+            key = key if key is not None else jax.random.PRNGKey(0)
+            prompt = jnp.asarray(prompt, jnp.int32)
+            if prompt.ndim == 1:
+                prompt = prompt[None]
+            tx_names = (self.schedule(task, receiver, n_tx)
+                        if proto.needs_transmitters() else [])
+            if proto.needs_transmitters() and not tx_names:
+                raise ValueError(
+                    f"protocol {protocol!r} requested but no transmitter "
+                    f"with a fuser for receiver {receiver!r} is schedulable; "
+                    f"submit with protocol='standalone' to run unrefined")
+            with jax.profiler.TraceAnnotation("fr.prepare"):
+                prep = proto.prepare(self, receiver, prompt, tx_names,
+                                     steps=steps, key=key, gated=gated,
+                                     tx_prompts=tx_prompts)
+            return eng.submit(prep.prompt, steps, fused=prep.fused,
+                              protocol=proto.name,
+                              meta={"transmitters": tx_names,
+                                    "wire_bytes": prep.wire_bytes}
+                              if tx_names else {})
 
     def drain(self, receiver: str) -> Dict[int, dict]:
         """Run the receiver's engine until idle; {rid: completion dict}."""

@@ -122,6 +122,7 @@ Quickstart::
 from __future__ import annotations
 
 import math
+import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -153,6 +154,7 @@ class EngineRequest:
     protocol: str = "standalone"
     meta: dict = field(default_factory=dict)
     digest: Optional[str] = None
+    submitted: float = 0.0  # time.perf_counter() at the queue append
 
 
 @dataclass
@@ -303,7 +305,10 @@ class ContinuousBatchingEngine:
                       "suffix_prefill_traces": 0,
                       "shared_admits": 0, "radix_hits": 0,
                       "radix_matched_tokens": 0, "cow_copies": 0,
-                      "fused_inserts": 0, "fused_digest_hits": 0}
+                      "fused_inserts": 0, "fused_digest_hits": 0,
+                      # summed us from the queue append to leaving the
+                      # queue (slot reserved or admitted), and how many left
+                      "queue_wait_us": 0, "reserved": 0}
         self._decode = jax.jit(self._make_decode())
         self._prefill = jax.jit(self._make_prefill())
         if paged:
@@ -472,50 +477,54 @@ class ContinuousBatchingEngine:
         ``digest`` names the fused prefix's content identity (computed from
         its bytes when omitted): requests sharing a digest share one inserted
         fused row, and — with the prefix cache — can share prompt pages."""
-        prompt = jnp.asarray(prompt, jnp.int32)
-        if prompt.ndim == 1:
-            prompt = prompt[None]
-        if prompt.shape[0] != 1:
-            raise ValueError("submit() takes one request at a time (B=1)")
-        if max_new_tokens < 1:
-            raise ValueError("max_new_tokens must be >= 1")
-        S = int(prompt.shape[1])
-        if S >= self.max_seq:
-            # checked before the combined bound so the degenerate case gets
-            # its own name: bucket rounding (_bucket_len) clamps at max_seq,
-            # and a prompt that large would land in a bucket with zero
-            # headroom for even the first decoded token
-            raise ValueError(
-                f"prompt({S}) fills the whole max_seq={self.max_seq} cache: "
-                "no headroom for the first decoded token")
-        if S + max_new_tokens > self.max_seq:
-            raise ValueError(f"prompt({S}) + gen({max_new_tokens}) exceeds "
-                             f"max_seq={self.max_seq}")
-        # 1-token requests are answered at prefill and own no pages — except
-        # under chunked prefill, which leases pages for the prompt itself
-        if self.paged and (max_new_tokens > 1 or self.prefill_budget):
-            need = math.ceil((S + max_new_tokens - 1) / self.page_size)
-            if need > self._table.num_pages:
-                raise ValueError(
-                    f"request needs {need} pages but the pool only has "
-                    f"{self._table.num_pages}; it could never be admitted")
-        if fused is not None:
-            if not self.max_prefix:
-                raise ValueError("engine built with max_prefix=0 cannot take "
-                                 "fused prefixes")
-            fused = self.held_prefix(fused)
-            if digest is None:
-                digest = fused_digest(fused)
-        else:
-            digest = None  # standalone requests share the None radix root
-        proto = protocol or ("c2c" if fused is not None else "standalone")
         rid = self._next_rid
-        self._next_rid += 1
-        req = EngineRequest(rid, prompt, max_new_tokens, fused, proto,
-                            meta or {}, digest)
-        self._queue.append(req)
-        self._req_info[rid] = req
-        return rid
+        with jax.profiler.TraceAnnotation("fr.enqueue", rid=rid):
+            prompt = jnp.asarray(prompt, jnp.int32)
+            if prompt.ndim == 1:
+                prompt = prompt[None]
+            if prompt.shape[0] != 1:
+                raise ValueError(
+                    "submit() takes one request at a time (B=1)")
+            if max_new_tokens < 1:
+                raise ValueError("max_new_tokens must be >= 1")
+            S = int(prompt.shape[1])
+            if S >= self.max_seq:
+                # checked before the combined bound so the degenerate case
+                # gets its own name: bucket rounding (_bucket_len) clamps at
+                # max_seq, and a prompt that large would land in a bucket
+                # with zero headroom for even the first decoded token
+                raise ValueError(
+                    f"prompt({S}) fills the whole max_seq={self.max_seq} "
+                    "cache: no headroom for the first decoded token")
+            if S + max_new_tokens > self.max_seq:
+                raise ValueError(f"prompt({S}) + gen({max_new_tokens}) "
+                                 f"exceeds max_seq={self.max_seq}")
+            # 1-token requests are answered at prefill and own no pages —
+            # except under chunked prefill, which leases pages for the
+            # prompt itself
+            if self.paged and (max_new_tokens > 1 or self.prefill_budget):
+                need = math.ceil((S + max_new_tokens - 1) / self.page_size)
+                if need > self._table.num_pages:
+                    raise ValueError(
+                        f"request needs {need} pages but the pool only has "
+                        f"{self._table.num_pages}; it could never be "
+                        f"admitted")
+            if fused is not None:
+                if not self.max_prefix:
+                    raise ValueError("engine built with max_prefix=0 "
+                                     "cannot take fused prefixes")
+                fused = self.held_prefix(fused)
+                if digest is None:
+                    digest = fused_digest(fused)
+            else:
+                digest = None  # standalone requests share the None root
+            proto = protocol or ("c2c" if fused is not None else "standalone")
+            self._next_rid += 1
+            req = EngineRequest(rid, prompt, max_new_tokens, fused, proto,
+                                meta or {}, digest, time.perf_counter())
+            self._queue.append(req)
+            self._req_info[rid] = req
+            return rid
 
     def held_prefix(self, fused=None) -> FusedPrefix:
         """A request's fused prefix as the engine holds it: padded to
@@ -525,9 +534,11 @@ class ContinuousBatchingEngine:
         row table anyway."""
         if fused is None:
             return self._empty_req_fused
-        fused = FusedPrefix.ensure(fused).pad(self.max_prefix)
-        return FusedPrefix(k=fused.k.astype(self.cache_dtype),
-                           v=fused.v.astype(self.cache_dtype), bias=fused.bias)
+        with jax.profiler.TraceAnnotation("fr.pad"):
+            fused = FusedPrefix.ensure(fused).pad(self.max_prefix)
+            return FusedPrefix(k=fused.k.astype(self.cache_dtype),
+                               v=fused.v.astype(self.cache_dtype),
+                               bias=fused.bias)
 
     # -------------------------------------------------------------- admission
     def _bucket_len(self, S: int) -> int:
@@ -628,7 +639,15 @@ class ContinuousBatchingEngine:
             taken_idx.append(i)
         for i in reversed(taken_idx):
             del self._queue[i]
+        for req in batch:
+            self._left_queue(req)
         return batch
+
+    def _left_queue(self, req: EngineRequest) -> None:
+        """Count ``req``'s wait in the queue, now that it left it."""
+        self.stats["queue_wait_us"] += round(
+            (time.perf_counter() - req.submitted) * 1e6)
+        self.stats["reserved"] += 1
 
     def _ensure_pages(self, need: int) -> bool:
         """Make ``need`` pages allocatable, evicting LRU prefix-index entries
@@ -796,7 +815,7 @@ class ContinuousBatchingEngine:
             if match is not None:
                 if not self._admit_shared(head, free[0], match):
                     break  # pool can't cover the unshared suffix: wait
-                self._queue.popleft()
+                self._left_queue(self._queue.popleft())
                 continue
             if self.paged and head.max_new_tokens > 1:
                 # pool pressure may be index pins, not live slots — evict
@@ -956,7 +975,7 @@ class ContinuousBatchingEngine:
                   self._reserve_fresh(head, free[0]))
             if not ok:
                 break  # head-of-line blocked on pages: wait for evictions
-            self._queue.popleft()
+            self._left_queue(self._queue.popleft())
 
     def _run_chunks(self) -> None:
         """Spend up to ``prefill_token_budget`` prompt tokens on the oldest
@@ -986,23 +1005,27 @@ class ContinuousBatchingEngine:
                 self._san.note_write(np.unique(pages[pages != invalid]),
                                      part.lease,
                                      what=f"chunk prefill (slot {part.slot})")
-            rf = req.fused if req.fused is not None else self._empty_req_fused
-            final = part.done + n == S
-            adopt_len = S if final and req.max_new_tokens > 1 else 0
-            meta = np.zeros(4 + pps + C, np.int32)
-            meta[:4] = (part.done, n, part.slot, adopt_len)
-            meta[4:4 + pps] = part.row
-            meta[4 + pps:4 + pps + n] = \
-                part.host_prompt[part.done:part.done + n]
-            first, self._tok, self._table = self._chunk_prefill(
-                self.params, self._table, self._tok, jnp.asarray(meta), rf)
-            part.done += n
-            self.stats["prefill_tokens"] += n
-            self.stats["prefill_chunks"] += 1
-            if part.done == S:
-                self._partials.popleft()
-                self._activate_partial(part, first)
-                break  # one adoption per step: keep the stall envelope flat
+            with jax.profiler.TraceAnnotation("fr.chunk", rid=req.rid,
+                                              start=part.done, rows=n):
+                rf = (req.fused if req.fused is not None
+                      else self._empty_req_fused)
+                final = part.done + n == S
+                adopt_len = S if final and req.max_new_tokens > 1 else 0
+                meta = np.zeros(4 + pps + C, np.int32)
+                meta[:4] = (part.done, n, part.slot, adopt_len)
+                meta[4:4 + pps] = part.row
+                meta[4 + pps:4 + pps + n] = \
+                    part.host_prompt[part.done:part.done + n]
+                first, self._tok, self._table = self._chunk_prefill(
+                    self.params, self._table, self._tok, jnp.asarray(meta),
+                    rf)
+                part.done += n
+                self.stats["prefill_tokens"] += n
+                self.stats["prefill_chunks"] += 1
+                if part.done == S:
+                    self._partials.popleft()
+                    self._activate_partial(part, first)
+                    break  # one adoption per step: keep the stall flat
 
     def _activate_partial(self, part: _PartialPrefill, first) -> None:
         """A prompt's final chunk landed: book-keep its activation. The
@@ -1066,48 +1089,53 @@ class ContinuousBatchingEngine:
         Chunked mode (``prefill_token_budget``) replaces monolithic admission
         prefills with a reservation pass plus at most one token-budget's
         worth of chunk compute, so the decode cadence below stays bounded."""
-        if self.prefill_budget:
-            self._admit_chunked()
+        with jax.profiler.TraceAnnotation("fr.step"):
+            with jax.profiler.TraceAnnotation("fr.admit"):
+                if self.prefill_budget:
+                    self._admit_chunked()
+                else:
+                    self._admit()
             self._run_chunks()
-        else:
-            self._admit()
-        done, self._ready = self._ready, []
-        if not self._active.any():
+            done, self._ready = self._ready, []
+            if not self._active.any():
+                return done
+            with jax.profiler.TraceAnnotation("fr.decode"):
+                self._tok, self._table = self._decode(*self._decode_args())
+            self.stats["decode_steps"] += 1
+            with jax.profiler.TraceAnnotation("fr.sync"):
+                tok_host = np.asarray(self._tok)
+            if self._san is not None:
+                # the decode step scattered each active slot's new token into
+                # page pos//page_size at the slot's pre-increment position —
+                # validate those writes before evictions release any lease
+                pos_host = np.asarray(self._table.pos)
+                for s in np.nonzero(self._active)[0]:
+                    lease = self._leases[int(s)]
+                    idx = (int(pos_host[s]) - 1) // self.page_size
+                    if idx >= lease.num_pages:
+                        raise SanitizerError(
+                            f"decode wrote position {int(pos_host[s]) - 1} "
+                            f"of slot {int(s)}, past its lease of "
+                            f"{lease.num_pages} page(s)")
+                    self._san.note_write([int(lease.page_ids[idx])], lease,
+                                         what=f"decode write (slot {int(s)})")
+            with jax.profiler.TraceAnnotation("fr.retire"):
+                for s in np.nonzero(self._active)[0]:
+                    rid = self._slot_rid[s]
+                    self._outputs[rid].append(tok_host[s])
+                    self._remaining[s] -= 1
+                    if self._remaining[s] == 0:
+                        self._active[s] = False
+                        self._slot_rid[s] = None
+                        self._evict(int(s))
+                        done.append(self._finish(rid))
+            if self._san is not None:
+                # allocator / shadow-state / device page-map agreement, after
+                # this step's admissions, decode writes and evictions landed
+                self._san.check_step(np.asarray(self._table.page_map),
+                                     self._active, self._leases,
+                                     self._table.invalid_page)
             return done
-        self._tok, self._table = self._decode(*self._decode_args())
-        self.stats["decode_steps"] += 1
-        tok_host = np.asarray(self._tok)
-        if self._san is not None:
-            # the decode step scattered each active slot's new token into
-            # page pos//page_size at the slot's pre-increment position —
-            # validate those writes before evictions release any lease
-            pos_host = np.asarray(self._table.pos)
-            for s in np.nonzero(self._active)[0]:
-                lease = self._leases[int(s)]
-                idx = (int(pos_host[s]) - 1) // self.page_size
-                if idx >= lease.num_pages:
-                    raise SanitizerError(
-                        f"decode wrote position {int(pos_host[s]) - 1} of "
-                        f"slot {int(s)}, past its lease of "
-                        f"{lease.num_pages} page(s)")
-                self._san.note_write([int(lease.page_ids[idx])], lease,
-                                     what=f"decode write (slot {int(s)})")
-        for s in np.nonzero(self._active)[0]:
-            rid = self._slot_rid[s]
-            self._outputs[rid].append(tok_host[s])
-            self._remaining[s] -= 1
-            if self._remaining[s] == 0:
-                self._active[s] = False
-                self._slot_rid[s] = None
-                self._evict(int(s))
-                done.append(self._finish(rid))
-        if self._san is not None:
-            # allocator / shadow-state / device page-map agreement, after
-            # this step's admissions, decode writes and evictions all landed
-            self._san.check_step(np.asarray(self._table.page_map),
-                                 self._active, self._leases,
-                                 self._table.invalid_page)
-        return done
 
     # ----------------------------------------------------------------- drain
     def drain(self) -> List[Completion]:

@@ -323,14 +323,19 @@ def fused_digest(fused) -> str:
     transmitted *once* is inserted once and every later request fusing the
     same digest reuses that row — and prompt pages are only shared between
     requests that attended the same fused prefix during prefill."""
-    f = FusedPrefix.ensure(fused)
-    h = hashlib.sha1()
-    for leaf in (f.k, f.v, f._bias_or_zero()):
-        arr = np.asarray(leaf)
-        h.update(str(arr.shape).encode())
-        h.update(str(arr.dtype).encode())
-        h.update(arr.tobytes())
-    return h.hexdigest()
+    with jax.profiler.TraceAnnotation("fr.digest"):
+        f = FusedPrefix.ensure(fused)
+        # the copy to the host, which first waits for the device to make it
+        with jax.profiler.TraceAnnotation("fr.fetch"):
+            arrs = [np.asarray(leaf)
+                    for leaf in (f.k, f.v, f._bias_or_zero())]
+        with jax.profiler.TraceAnnotation("fr.hash"):
+            h = hashlib.sha1()
+            for arr in arrs:
+                h.update(str(arr.shape).encode())
+                h.update(str(arr.dtype).encode())
+                h.update(arr.tobytes())
+            return h.hexdigest()
 
 
 # ------------------------------------------------------------------ KVCache
