@@ -1206,7 +1206,10 @@ class ContinuousBatchingEngine:
         *current* occupancy (call it mid-flight).
 
         ``paged_kernel`` counts only the pages that hold live tokens — what
-        the in-place kernel DMAs (Σ_active ceil((pos+1)/page_size) pages).
+        the in-place kernel DMAs (Σ_active ceil((pos+1)/page_size) pages,
+        also given as ``paged_pages_live``); ``paged_blocks_live`` counts the
+        page blocks its per-slot loop runs over (Σ_active ceil(pages / P),
+        P from ``kernels.paged_attention.block_pages``).
         ``dense_gather`` counts every slot's full row — what the
         ``dense_view()`` gather path reads no matter how little of each slot
         is live (slots × view_seq for paged-gather, slots × max_seq dense).
@@ -1217,11 +1220,17 @@ class ContinuousBatchingEngine:
             * itemsize * n_entries  # k+v bytes per cached token
         pos = np.asarray(self._table.pos)
         if self.paged:
+            from repro.kernels.paged_attention import block_pages
+
             pg = self.page_size
-            live = pos[self._active] + 1
-            pages = int(np.sum(-(-live // pg)))  # ceil
+            pool_shape = self._table.layers[0]["k"].shape[1:]
+            P = block_pages(pool_shape, itemsize, self._table.pages_per_slot)
+            live = -(-(pos[self._active] + 1) // pg)  # ceil: pages per slot
+            pages = int(np.sum(live))
             view_seq = self._table.view_seq
             return {"paged_kernel": pages * pg * row_bytes,
+                    "paged_pages_live": pages,
+                    "paged_blocks_live": int(np.sum(-(-live // P))),
                     "dense_gather": self.max_slots * view_seq * row_bytes}
         return {"paged_kernel": 0,
                 "dense_gather": self.max_slots * self.max_seq * row_bytes}

@@ -611,8 +611,11 @@ class SlotTable:
     def attend(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                page_map: jax.Array, lengths: jax.Array):
         """GQA flash-decode over the page pool *in place* — the hot-loop path
-        that replaces ``dense_view()`` gathering (kernels/paged_attention.py
-        walks the page map with scalar prefetch and skips INVALID pages).
+        that replaces ``dense_view()`` gathering. kernels/paged_attention.py
+        runs one grid step per slot, all kv heads at once, and loops over
+        only the slot's live blocks of P page-map entries (P from the page's
+        byte size), copying each mapped page inside the length from the pool
+        and skipping INVALID ones.
 
         q (slots, H, hd); pools (num_pages, Hkv, page_size, hd); lengths
         (slots,) live tokens. Returns (out (slots, H, hd), m, l) online

@@ -440,6 +440,30 @@ def test_kv_read_bytes_per_step_accounting(cfg, params):
     assert b["paged_kernel"] < b["dense_gather"]
 
 
+def test_kv_read_bytes_per_step_counts_live_page_blocks(cfg, params):
+    """The engagement counter: the paged kernel loops over
+    ceil(ceil((pos+1)/page_size)/P) page blocks per active slot, with P as
+    the kernel picks it from the pool's shape."""
+    from repro.kernels.paged_attention import block_pages
+
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=4, max_seq=64,
+                                   paged=True, page_size=8)
+    for i, n in enumerate((5, 13, 20)):
+        eng.submit(_prompt(jax.random.fold_in(jax.random.PRNGKey(29), i), n),
+                   6)
+    pool = eng._table.layers[0]["k"]
+    P = block_pages(pool.shape[1:], pool.dtype.itemsize,
+                    eng._table.pages_per_slot)
+    for _ in range(3):
+        eng.step()
+        b = eng.kv_read_bytes_per_step()
+        pages = -(-(np.asarray(eng._table.pos)[eng._active] + 1) // 8)
+        assert b["paged_pages_live"] == int(pages.sum()) > 0
+        assert b["paged_blocks_live"] == int((-(-pages // P)).sum())
+        assert b["paged_kernel"] == b["paged_pages_live"] * 8 * (
+            b["dense_gather"] // (4 * 64))
+
+
 def test_paged_requires_pure_attention():
     from repro.configs.base import get_smoke_config
 

@@ -257,30 +257,103 @@ def _paged_case(page_size, *, dt=jnp.float32):
     return q, k_pool, v_pool, pm, lengths
 
 
-@pytest.mark.parametrize("page_size", [8, 16, 64])
-def test_paged_decode_attention_matches_gather_ref(page_size):
-    """In-place page-map walk == gather-then-attend oracle, across page sizes,
-    partial final pages and interleaved INVALID_PAGE entries."""
-    q, k_pool, v_pool, pm, lengths = _paged_case(page_size)
+# geometries (Hkv, G, hd, page_size) at which a slot spans several of the
+# kernel's page blocks; the last is Qwen3-0.6B's, the one before Qwen2.5-0.5B's
+_BLOCK_GEOMETRY = {
+    "edges": (2, 4, 32, 16),
+    "holes": (2, 4, 32, 16),
+    "qwen2.5-0.5b": (2, 7, 64, 16),
+    "qwen3-0.6b": (8, 2, 128, 16),
+}
+
+
+def _paged_blocks_case(name, itemsize):
+    """Slots over several page blocks: P pages a block, as the kernel picks
+    it for a pool of this itemsize, and 2P + 3 pages a slot, so the map is no
+    multiple of P wide and a full slot wraps the double buffer. Every slot
+    maps real pages past its length, as a lease does. ``edges``: lengths on
+    a block boundary, one past it, inside the first page, 0 and full.
+    ``holes``: INVALID pages inside the length and inside one block, a live
+    slot whose second block maps no page, and an evicted slot."""
+    from repro.kernels.paged_attention import block_pages
+    Hkv, G, hd, pg = _BLOCK_GEOMETRY[name]
+    P = block_pages((1, Hkv, pg, hd), itemsize, 1 << 20)
+    pps, blk = 2 * P + 3, P * pg
+    if name == "holes":
+        lengths = [pps * pg, 2 * blk + 5, blk + 7, 2]
+        holes = ([(0, 1), (0, 2), (0, pps - 1)]
+                 + [(1, P + j) for j in range(P)] + [(2, P)]
+                 + [(3, j) for j in range(pps)])
+    else:
+        lengths, holes = [blk, blk + 1, 3, 0, pps * pg], []
+    slots = len(lengths)
+    num_pages = slots * pps
+    ks = jax.random.split(jax.random.fold_in(KEY, len(name)), 4)
+    q = jax.random.normal(ks[0], (slots, Hkv * G, hd), jnp.float32)
+    k_pool, v_pool = (jax.random.normal(k, (num_pages, Hkv, pg, hd),
+                                        jnp.float32) for k in ks[1:3])
+    pm = jax.random.permutation(ks[3], num_pages).reshape(slots, pps)
+    for s, j in holes:
+        pm = pm.at[s, j].set(num_pages)
+    return q, k_pool, v_pool, pm.astype(jnp.int32), jnp.array(lengths,
+                                                              jnp.int32)
+
+
+def _paged_cases(*page_sizes):
+    return ([pytest.param(pg, id=str(pg)) for pg in page_sizes]
+            + list(_BLOCK_GEOMETRY))
+
+
+def _case(case, itemsize):
+    if isinstance(case, int):
+        return _paged_case(case)
+    return _paged_blocks_case(case, itemsize)
+
+
+def _check_paged(out, l, q, k_pool, v_pool, pm, lengths):
+    """Kernel output == gather oracle; a slot with no live key returns zeros
+    with zero attention mass (hardened finish), every other slot has l > 0."""
     slots, H, hd = q.shape
     Hkv = k_pool.shape[1]
-    out, m, l = ops.paged_decode_attention(q, k_pool, v_pool, pm, lengths)
     oref = ref.paged_decode_attention_ref(
         q.reshape(slots, Hkv, H // Hkv, hd), k_pool, v_pool, pm,
         lengths).reshape(slots, H, hd)
     assert float(jnp.abs(out - oref).max()) < 1e-4
-    # evicted slot: zeros with zero attention mass (hardened finish)
-    assert float(jnp.abs(out[3]).max()) == 0.0
-    assert float(l[3].max()) == 0.0
-    assert bool((l[:3] > 0).all())
+    t = jnp.arange(pm.shape[1] * k_pool.shape[2])
+    mapped = jnp.repeat(pm < k_pool.shape[0], k_pool.shape[2], axis=1)
+    seen = (mapped & (t < lengths[:, None])).any(axis=1)
+    assert float(jnp.abs(jnp.where(seen[:, None, None], 0.0, out)).max()) == 0
+    assert bool(jnp.where(seen[:, None], l > 0, l == 0).all())
 
 
-@pytest.mark.parametrize("page_size", [8, 16])
-def test_paged_decode_attention_q8_matches_ref(page_size):
-    q, k_pool, v_pool, pm, lengths = _paged_case(page_size)
-    slots, H, hd = q.shape
+@pytest.mark.parametrize("pool_shape,itemsize,pps,P", [
+    ((8192, 8, 16, 128), 2, 256, 16),  # Qwen3-0.6B, bf16: the cells' pool
+    ((8192, 8, 16, 128), 1, 256, 32),  # the same pool in int8
+    ((1024, 2, 16, 64), 2, 128, 64),   # Qwen2.5-0.5B: heads padded to 128
+    ((64, 2, 8, 32), 4, 4, 4),         # a small pool: one block a slot
+])
+def test_paged_block_pages_from_shape(pool_shape, itemsize, pps, P):
+    """P, the pages of one block, comes from the page's byte size alone: the
+    most pages whose K block fits in BLOCK_BYTES, at most a slot's map."""
+    from repro.kernels.paged_attention import block_pages
+    assert block_pages(pool_shape, itemsize, pps) == P
+
+
+@pytest.mark.parametrize("case", _paged_cases(8, 16, 64))
+def test_paged_decode_attention_matches_gather_ref(case):
+    """In-place page-map walk == gather-then-attend oracle, across page sizes,
+    partial final pages, interleaved INVALID_PAGE entries and slots that
+    span several page blocks."""
+    q, k_pool, v_pool, pm, lengths = _case(case, 4)
+    out, m, l = ops.paged_decode_attention(q, k_pool, v_pool, pm, lengths)
+    _check_paged(out, l, q, k_pool, v_pool, pm, lengths)
+
+
+@pytest.mark.parametrize("case", _paged_cases(8, 16))
+def test_paged_decode_attention_q8_matches_ref(case):
+    q, k_pool, v_pool, pm, lengths = _case(case, 1)
     Hkv = k_pool.shape[1]
-    num_pages = k_pool.shape[0]
+    num_pages, hd = k_pool.shape[0], k_pool.shape[3]
     sk = jnp.max(jnp.abs(k_pool), axis=2, keepdims=True) / 127.0
     sv = jnp.max(jnp.abs(v_pool), axis=2, keepdims=True) / 127.0
     qpool = {
@@ -290,11 +363,8 @@ def test_paged_decode_attention_q8_matches_ref(page_size):
     }
     assert qpool["k_scale"].shape == (num_pages, Hkv, 1, hd)
     out, _, l = ops.paged_decode_attention_q8(q, qpool, pm, lengths)
-    oref = ref.paged_decode_attention_ref(
-        q.reshape(slots, Hkv, H // Hkv, hd), qpool["k_q"] * sk,
-        qpool["v_q"] * sv, pm, lengths).reshape(slots, H, hd)
-    assert float(jnp.abs(out - oref).max()) < 1e-4
-    assert float(l[3].max()) == 0.0
+    _check_paged(out, l, q, qpool["k_q"] * sk, qpool["v_q"] * sv, pm,
+                 lengths)
 
 
 def test_paged_decode_attention_lse_stats_merge():

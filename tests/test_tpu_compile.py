@@ -5,7 +5,8 @@ The TPU compiler is installed with jax; it compiles for a described
 block-shape rules, VMEM limits, unaligned slices. The kernels run at the
 widths of the case-study pairing (Qwen3-0.6B receiver, Qwen2.5-0.5B
 transmitter) and the chip smoke run's geometry: 8 slots of 2,048 tokens in
-16-token bf16 pages, 256-token prefill chunks.
+16-token bf16 pages, 256-token prefill chunks; the paged decode kernel also
+at the benchmark cells' serving geometry.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -65,16 +66,21 @@ def _compiled_text(fn, args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c.name)
-def test_paged_decode_compiles_for_v5e(cfg, one_chip, no_persistent_cache):
+@pytest.mark.parametrize("cfg,slots,pps,num_pages", [
+    pytest.param(c, SLOTS, PPS, NUM_PAGES, id=c.name) for c in CONFIGS] + [
+    # the benchmark cells' serving geometry: 32 slots of 256 pages mapped
+    # into a 2,048-page pool
+    pytest.param(QWEN3_0_6B, 32, 256, 2048, id="qwen3-0.6b-serve")])
+def test_paged_decode_compiles_for_v5e(cfg, slots, pps, num_pages, one_chip,
+                                       no_persistent_cache):
     hkv, g, hd = _dims(cfg)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = s((NUM_PAGES, hkv, PAGE, hd), jnp.bfloat16)
-    args = (s((SLOTS, hkv, g, hd), jnp.bfloat16), pool, pool,
-            s((SLOTS, PPS), jnp.int32), s((SLOTS,), jnp.int32))
+    pool = s((num_pages, hkv, PAGE, hd), jnp.bfloat16)
+    args = (s((slots, hkv, g, hd), jnp.bfloat16), pool, pool,
+            s((slots, pps), jnp.int32), s((slots,), jnp.int32))
     text = _compiled_text(paged_decode_attention_pallas, args)
     assert "tpu_custom_call" in text
 
