@@ -72,12 +72,14 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
     dev = devices[0]
     peak = peaks(dev.device_kind) if require_chip else None
     conf, traffic = cell.config, cell.traffic
-    rx_cfg, tx_cfg = model.configs(conf)
+    archs = [spec.architecture(conf[s], "arch") for s in model.SIDES]
+    refs = [spec.architecture(conf[s], "ref") for s in model.SIDES]
+    rx_cfg, tx_cfg = model.configs(conf, archs)
     dtype = model.DTYPES[conf["dtype"]]
 
     t0 = time.perf_counter()
-    weights = jax.block_until_ready(
-        model.make_weights(rx_cfg, tx_cfg, seed, dtype))
+    weights = jax.block_until_ready(model.make_weights(
+        rx_cfg, tx_cfg, seed, dtype, rules=[a.WEIGHT_RULES for a in archs]))
     system = model.build_system(rx_cfg, tx_cfg, weights)
     eng = model.make_engine(system, rx_cfg, conf["engine"])
     if engine_hook is not None:
@@ -134,7 +136,7 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
                           chk["sample_requests"])
     ref = Reference(conf, weights, mix.longest(), max(
         [c["prompt"]["max"] for c in traffic["classes"]
-         if c["protocol"] == "c2c"] or [16]))
+         if c["protocol"] == "c2c"] or [16]), refs)
     t0 = time.perf_counter()
     readings = check.compare(ref, chosen, control=control)
     log(f"reference: {readings['requests']} requests, {readings['tokens']} "
@@ -148,8 +150,8 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
     correct = check.verdict(readings[judged], limit, len(chosen), problems,
                             failed)
 
-    run = Run(cell, window, setup_s, Dims.of(conf["receiver"]),
-              Dims.of(conf["transmitter"]), conf["engine"],
+    run = Run(cell, window, setup_s, refs[0].dims(conf["receiver"]),
+              refs[1].dims(conf["transmitter"]), conf["engine"],
               jnp.dtype(model.DTYPES[conf["engine"]["cache_dtype"]]).itemsize,
               peak, summary)
     metrics = {}

@@ -25,30 +25,17 @@ from repro.core.registry import FuserRegistry
 from repro.models import transformer as T
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+SIDES = ("receiver", "transmitter")
 
 
-def model_config(name: str, hf: Dict) -> ModelConfig:
-    """The program's config for one model of the configuration file."""
-    if hf.get("model_type") not in ("qwen2", "qwen3"):
-        raise ValueError(f"{name}: model_type {hf.get('model_type')!r} is not "
-                         f"a dense Qwen2/Qwen3 decoder")
-    heads = hf["num_attention_heads"]
-    return ModelConfig(
-        name=name, family="dense",
-        num_layers=hf["num_hidden_layers"], d_model=hf["hidden_size"],
-        num_heads=heads, num_kv_heads=hf["num_key_value_heads"],
-        head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
-        d_ff=hf["intermediate_size"], vocab_size=hf["vocab_size"],
-        qkv_bias=bool(hf["attention_bias"]), qk_norm=bool(hf["qk_norm"]),
-        rope_theta=float(hf["rope_theta"]),
-        tie_embeddings=bool(hf["tie_word_embeddings"]),
-        norm_eps=float(hf["rms_norm_eps"]), source=hf["source"])
-
-
-def configs(config: Dict) -> Tuple[ModelConfig, ModelConfig]:
-    return (model_config(config["receiver"]["name"], config["receiver"]),
-            model_config(config["transmitter"]["name"],
-                         config["transmitter"]))
+def configs(config: Dict, archs) -> Tuple[ModelConfig, ModelConfig]:
+    """The program's configs of the receiver and the transmitter, each
+    mapped by the ``program_config`` of its architecture (``archs``, the
+    program sides found by ``Spec.architecture``, in the order of
+    ``SIDES``)."""
+    rx, tx = (a.program_config(config[s]["name"], config[s])
+              for a, s in zip(archs, SIDES))
+    return rx, tx
 
 
 def alignment(n_rx: int, n_tx: int) -> np.ndarray:
@@ -57,13 +44,15 @@ def alignment(n_rx: int, n_tx: int) -> np.ndarray:
     return np.minimum(np.arange(n_rx), n_tx - 1).astype(np.int32)
 
 
-def _leaf_value(path: str, shape, dtype, key):
+def _leaf_value(path: str, shape, dtype, key, rules: Dict):
     """The benchmark's random value for one weight, by its name in the
     program's layout: weights N(0, 1/fan_in), so every block adds O(1) to
     the residual stream; the (tied) embedding N(0, 0.02^2), the published
     ``initializer_range``, so the prediction rests on the context and not on
     the input token alone; biases N(0, 0.1^2), norm scales 1 + N(0, 0.1^2),
-    fuser gates -1 + N(0, 0.5^2)."""
+    fuser gates -1 + N(0, 0.5^2). A leaf none of these name takes the rule
+    its architecture adds under its name (``rules``: name -> f(z, shape),
+    with z the same N(0, 1) draw)."""
     leaf = path.rsplit("/", 1)[-1]
     z = jax.random.normal(key, shape, jnp.float32)
     if leaf == "table":
@@ -76,6 +65,8 @@ def _leaf_value(path: str, shape, dtype, key):
         x = 1.0 + 0.1 * z
     elif leaf == "gate":
         x = -1.0 + 0.5 * z
+    elif leaf in rules:
+        x = rules[leaf](z, shape)
     else:
         raise KeyError(f"no rule for weight {path!r}")
     return x.astype(dtype)
@@ -89,10 +80,12 @@ def _path_name(path) -> str:
 
 
 def make_weights(rx: ModelConfig, tx: ModelConfig, seed: int,
-                 dtype=jnp.bfloat16) -> Dict:
+                 dtype=jnp.bfloat16, *, rules) -> Dict:
     """Receiver, transmitter, fuser (transmitter -> receiver) and gate, from
     ``seed``, in one jitted call on the default device. Model weights are
-    ``dtype``; norms, fuser and gate keep the program's float32."""
+    ``dtype``; norms, fuser and gate keep the program's float32. ``rules``:
+    the ``WEIGHT_RULES`` of the receiver's and the transmitter's
+    architectures."""
     k0 = jax.random.PRNGKey(0)
     shapes = {
         "rx": jax.eval_shape(lambda k: T.init_params(rx, k, dtype), k0),
@@ -103,6 +96,7 @@ def make_weights(rx: ModelConfig, tx: ModelConfig, seed: int,
     align = alignment(len(rx.attention_layers), len(tx.attention_layers))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     names = [_path_name(p) for p, _ in flat]
+    part_rules = {"rx": rules[0], "tx": rules[1]}
 
     def fill(key):
         leaves = []
@@ -111,7 +105,8 @@ def make_weights(rx: ModelConfig, tx: ModelConfig, seed: int,
                 leaves.append(jnp.asarray(align, s.dtype))
                 continue
             k = jax.random.fold_in(key, zlib.crc32(name.encode()) % 2**31)
-            leaves.append(_leaf_value(name, s.shape, s.dtype, k))
+            leaves.append(_leaf_value(name, s.shape, s.dtype, k,
+                                      part_rules.get(name.split("/")[0], {})))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return jax.jit(fill)(jax.random.PRNGKey(seed % 2**31))

@@ -8,14 +8,22 @@ an entry, never an edit:
 - configuration ``<c>``: the ``file`` its entry names (``bench/configs/``);
 - traffic mix ``<t>``: ``bench/traffic/<t>.json``;
 - metric ``<m>``: ``bench/metrics/<m>.py``, a reader with ``read(run)``
-  returning a number, or None where the run holds nothing to read.
+  returning a number, or None where the run holds nothing to read;
+- architecture ``<a>``, the ``model_type`` of a model in a configuration:
+  ``bench/arch/<a>.py``, the program's side (``program_config(name, hf)``,
+  the program's ``ModelConfig``, and ``WEIGHT_RULES``, the weight rules it
+  adds to ``model.py``'s common ones), and ``bench/ref/<a>.py``, the plain
+  reference's side (``block``, one decoder layer, and ``dims(hf)``, the
+  sizes and counts ``work.py`` reads), which imports nothing of the program.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 #: The checkout's root: ``bench/harness`` is two levels below it.
@@ -26,6 +34,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 def load_json(path: str) -> Dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: str, what: str) -> ModuleType:
+    """The Python file at ``path``, loaded by its path (not imported as a
+    package), so that a checkout brings its own; ``what`` names it in the
+    error where the file is missing."""
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {what}: {path} does not exist")
+    rel = os.path.splitext(os.path.basename(path))[0]
+    name = "bench_" + os.path.basename(os.path.dirname(path)) + "_" + "".join(
+        ch if ch.isalnum() else "_" for ch in rel)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # where a dataclass in it looks its module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclass
@@ -80,14 +104,17 @@ class Spec:
 
     def reader(self, metric: str) -> Callable:
         path = os.path.join(self.root, "bench", "metrics", f"{metric}.py")
-        mod_name = "bench_metric_" + "".join(
-            ch if ch.isalnum() else "_" for ch in metric)
-        spec = importlib.util.spec_from_file_location(mod_name, path)
-        if spec is None or spec.loader is None:
-            raise FileNotFoundError(f"no reader for metric {metric!r}: {path}")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(path, f"reader for metric {metric!r}").read
+
+    # -------------------------------------------------------- architectures
+    def architecture(self, hf: Dict, side: str) -> ModuleType:
+        """One side of the architecture of the model ``hf`` (a model section
+        of a configuration file), found by its ``model_type``: ``side`` is
+        ``"arch"`` for the program's, ``"ref"`` for the reference's."""
+        kind = hf.get("model_type")
+        path = os.path.join(self.root, "bench", side, f"{kind}.py")
+        return load_module(path, f"architecture {kind!r} of model "
+                                 f"{hf.get('name')!r} ({side} side)")
 
     # --------------------------------------------------------------- cells
     def config(self, name: str) -> Dict:

@@ -2,18 +2,14 @@
 
 These are the benchmark's own counts, from the configuration file's sizes,
 so no change to the program can move them. FLOPs count 2 per multiply-add.
-
-The per-token closed form of a dense decoder layer is a copy of
-``repro.roofline.flops_analytic`` (dense, full-attention terms): q/k/v/o
-projections, scores and attention-weighted values over the keys attended,
-and the SwiGLU feed-forward network; the (tied) unembedding is
-``2 * d * vocab`` per token whose logits are needed.
+A model's own counts (its layers' FLOPs per token, its K/V row) come from
+its architecture's ``dims(hf)`` (``bench/ref/<model_type>.py``); the fuser,
+the transmit side and the kernels' needs are counted here from those.
 """
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Protocol
 
 #: Published peaks per chip, keyed by ``jax.Device.device_kind`` (a copy of
 #: ``repro.hw.PEAKS``). A chip missing here is an error, not a default.
@@ -33,57 +29,35 @@ def peaks(device_kind: str) -> Dict:
     return PEAKS[device_kind]
 
 
-@dataclass(frozen=True)
-class Dims:
-    """The sizes the counts need, read from a ``config.json``-style dict."""
+class Dims(Protocol):
+    """What the counts read of one model: an architecture's ``dims(hf)``."""
 
     layers: int
     d: int
     heads: int
     kv_heads: int
     head_dim: int
-    ffn: int
     vocab: int
 
-    @classmethod
-    def of(cls, hf: Dict) -> "Dims":
-        h = hf["num_attention_heads"]
-        return cls(hf["num_hidden_layers"], hf["hidden_size"], h,
-                   hf["num_key_value_heads"],
-                   hf.get("head_dim") or hf["hidden_size"] // h,
-                   hf["intermediate_size"], hf["vocab_size"])
-
-    # ------------------------------------------------------------- model
     def dense_flops(self) -> float:
         """Per token per layer, attention over the keys excluded."""
-        d, hd = self.d, self.head_dim
-        return (2 * d * (2 * self.heads * hd + 2 * self.kv_heads * hd)
-                + 2 * 3 * d * self.ffn)
 
     def attn_flops(self, keys: float) -> float:
         """Per query row per layer: scores and values over ``keys`` keys."""
-        return 2 * 2 * self.heads * self.head_dim * keys
 
     def head_flops(self) -> float:
-        return 2 * self.d * self.vocab
+        """The logits of one token."""
 
     def decode_token_flops(self, keys: int, prefix: int = 0) -> float:
         """One decoded token attending ``keys`` own and ``prefix`` fused
         keys, with its logits."""
-        return (self.layers * (self.dense_flops()
-                               + self.attn_flops(keys + prefix))
-                + self.head_flops())
 
     def prefill_flops(self, start: int, rows: int, prefix: int = 0) -> float:
-        """Causal prefill of rows ``[start, start + rows)`` (row i attends
-        i + 1 own keys and ``prefix`` fused keys), without logits."""
-        keys = rows * start + rows * (rows + 1) / 2 + rows * prefix
-        return self.layers * (rows * self.dense_flops()
-                              + self.attn_flops(keys))
+        """Causal prefill of rows ``[start, start + rows)``, without
+        logits."""
 
     def kv_row_bytes(self, itemsize: int) -> int:
         """K and V of one token in one layer."""
-        return 2 * self.kv_heads * self.head_dim * itemsize
 
 
 def fuser_flops(tx: Dims, rx: Dims, tokens: int) -> float:

@@ -41,12 +41,15 @@ def main() -> None:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--rates", required=True)
     args = ap.parse_args()
-    cell = Spec(ROOT).cell(args.workload)
+    spec = Spec(ROOT)
+    cell = spec.cell(args.workload)
     chip(cell)
     conf, traffic = cell.config, cell.traffic
-    rx, tx = model.configs(conf)
+    archs = [spec.architecture(conf[s], "arch") for s in model.SIDES]
+    rx, tx = model.configs(conf, archs)
     weights = model.make_weights(rx, tx, args.seed,
-                                 model.DTYPES[conf["dtype"]])
+                                 model.DTYPES[conf["dtype"]],
+                                 rules=[a.WEIGHT_RULES for a in archs])
     system = model.build_system(rx, tx, weights)
     for rate in (float(x) for x in args.rates.split(",")):
         eng = model.make_engine(system, rx, conf["engine"])

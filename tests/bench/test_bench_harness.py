@@ -25,7 +25,7 @@ from bench.harness.spec import Spec  # noqa: E402
 from bench.harness.traffic import (Mix, Request, class_counts,  # noqa: E402
                                    exponential_gaps, lognormal_quantiles,
                                    zipf_quantiles)
-from bench.harness.work import (Dims, paged_decode_need,  # noqa: E402
+from bench.harness.work import (paged_decode_need,  # noqa: E402
                                 ragged_prefill_need)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -43,13 +43,20 @@ TINY_ENGINE = dict(max_slots=4, max_seq=256, max_prefix=64,
 TINY_LIMIT = 0.1
 
 
+def _dims(hf):
+    """The counts of one model, by its architecture's reference side."""
+    return Spec(ROOT).architecture(hf, "ref").dims(hf)
+
+
 def _tiny_root(dst: str) -> str:
-    """A checkout-like directory: the real BENCHMARK.json, metrics and
-    traffic shapes, with a tiny configuration and tiny lengths."""
+    """A checkout-like directory: the real BENCHMARK.json, metrics,
+    architectures and traffic shapes, with a tiny configuration and tiny
+    lengths."""
     os.makedirs(os.path.join(dst, "bench", "configs"))
     os.makedirs(os.path.join(dst, "bench", "traffic"))
-    shutil.copytree(os.path.join(ROOT, "bench", "metrics"),
-                    os.path.join(dst, "bench", "metrics"))
+    for piece in ("metrics", "arch", "ref"):
+        shutil.copytree(os.path.join(ROOT, "bench", piece),
+                        os.path.join(dst, "bench", piece))
     bench = Spec(ROOT).bench
     conf = Spec(ROOT).config(bench["configs"][0]["name"])
     conf["receiver"].update(TINY_RX)
@@ -274,7 +281,7 @@ def test_served_flops_count_each_prompt_whole_however_chunked():
     from types import SimpleNamespace
 
     conf = Spec(ROOT).config("qwen3-0.6b_qwen2.5-0.5b")
-    rx, tx = Dims.of(conf["receiver"]), Dims.of(conf["transmitter"])
+    rx, tx = _dims(conf["receiver"]), _dims(conf["transmitter"])
 
     def flops(chunks, matched=0):
         a = _req(0, 100, 4, 0.0, ready=len(chunks) - 1)
@@ -330,7 +337,7 @@ def test_op_names():
 
 
 def test_flop_and_byte_counts_against_hand_counts():
-    rx = Dims.of(Spec(ROOT).config("qwen3-0.6b_qwen2.5-0.5b")["receiver"])
+    rx = _dims(Spec(ROOT).config("qwen3-0.6b_qwen2.5-0.5b")["receiver"])
     # q 1024x2048, k and v 1024x1024, o 2048x1024, SwiGLU 3 x 1024x3072:
     # 15,728,640 weights a layer, two FLOPs each
     assert rx.dense_flops() == 2 * 15_728_640
