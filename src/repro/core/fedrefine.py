@@ -21,7 +21,8 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from functools import partial
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,27 @@ from repro.core.protocol import PROTOCOLS
 from repro.core.registry import FuserRegistry
 from repro.models import transformer as T
 from repro.models.cache import FusedPrefix, KVStack
+
+
+@partial(jax.jit, static_argnums=0)
+def transmit_program(cfg: ModelConfig, params: dict,
+                     tokens: jax.Array) -> KVStack:
+    """Step 2 on the transmitter, as one program: prefill ``tokens`` (B, S)
+    and export the S rows of every attention layer. Built once per
+    (config, prompt shape); the weights are arguments, never constants."""
+    S = tokens.shape[1]
+    _, cache = T.prefill(cfg, params, tokens, max_seq=S)
+    return cache.export_stack(cfg, length=S)
+
+
+@partial(jax.jit, static_argnames=("cfg_txs", "cfg_rx", "use_kernel"))
+def fuse_program(fusers: List[dict], gating: Optional[dict],
+                 stacks: List[KVStack], *, cfg_txs: Tuple[ModelConfig, ...],
+                 cfg_rx: ModelConfig, use_kernel: bool = False) -> FusedPrefix:
+    """Steps 4-5 on the receiver, as one program: ``c2c.fused_prefix``.
+    ``gating=None`` runs ungated; its pytree structure keys the program."""
+    return c2c.fused_prefix(fusers, list(cfg_txs), cfg_rx, stacks,
+                            gating=gating, use_kernel=use_kernel)
 
 
 @dataclass
@@ -55,6 +77,12 @@ class FedRefineSystem:
     task_affinity: Dict[str, List[str]] = field(default_factory=dict)
     # receiver name -> continuous-batching engine (see make_engine/submit/drain)
     engines: Dict[str, object] = field(default_factory=dict)
+    # transmitter program calls, and the (config, prompt shape) programs
+    # they built; a warmed window adds calls only
+    stats: Dict[str, int] = field(
+        default_factory=lambda: {"tx_calls": 0, "tx_programs": 0})
+    _tx_shapes: Set[tuple] = field(default_factory=set, init=False,
+                                   repr=False)
 
     # ------------------------------------------------------------- setup
     @classmethod
@@ -108,11 +136,14 @@ class FedRefineSystem:
         stacks, wire_bytes = [], 0
         for n in tx_names:
             p = self.participants[n]
-            S = prompts[n].shape[1]
+            tokens = prompts[n]
             with jax.profiler.TraceAnnotation("fr.tx_prefill", tx=n):
-                _, cache = T.prefill(p.cfg, p.params, prompts[n], max_seq=S)
+                stack = transmit_program(p.cfg, p.params, tokens)
+            self._tx_shapes.add((p.cfg, tokens.shape))
+            self.stats["tx_calls"] += 1
+            self.stats["tx_programs"] = len(self._tx_shapes)
             with jax.profiler.TraceAnnotation("fr.tx_export"):
-                msg = TR.stack_message(cache.export_stack(p.cfg, length=S))
+                msg = TR.stack_message(stack)
             with jax.profiler.TraceAnnotation("fr.wire"):
                 received, nbytes = self.wire.transmit(msg)
             stacks.append(received.stack)
@@ -125,10 +156,11 @@ class FedRefineSystem:
         with jax.profiler.TraceAnnotation("fr.fuse"):
             rxp = self.participants[receiver]
             fusers = [self.registry.get(n, receiver) for n in tx_names]
-            cfg_txs = [self.participants[n].cfg for n in tx_names]
+            cfg_txs = tuple(self.participants[n].cfg for n in tx_names)
             gating = self.registry.ensure_gating(receiver) if gated else None
-            return c2c.fused_prefix(fusers, cfg_txs, rxp.cfg, stacks,
-                                    gating=gating, use_kernel=use_kernel)
+            return fuse_program(fusers, gating, stacks,
+                                cfg_txs=cfg_txs, cfg_rx=rxp.cfg,
+                                use_kernel=use_kernel)
 
     def refine_generate(
         self,

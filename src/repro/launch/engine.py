@@ -559,9 +559,12 @@ class ContinuousBatchingEngine:
         return min(b, self.max_seq)
 
     def _free_slots(self) -> List[int]:
-        # a slot mid-chunked-prefill is inactive but reserved (_slot_rid set)
-        return [i for i in range(self.max_slots)
-                if not self._active[i] and self._slot_rid[i] is None]
+        # a slot mid-chunked-prefill is inactive but reserved (_slot_rid set).
+        # The queue's head takes slot rid % max_slots or the next free one
+        # after it, so a lightly loaded engine still serves from every slot.
+        n, start = self.max_slots, self._queue[0].rid
+        return [i % n for i in range(start, start + n)
+                if not self._active[i % n] and self._slot_rid[i % n] is None]
 
     def _pages_needed(self, req: EngineRequest) -> int:
         # Highest position ever *written* is S + max_new - 2 (the final
